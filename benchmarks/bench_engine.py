@@ -110,4 +110,6 @@ def run(n_gallery: int = 2048, dim: int = 128, n_queries: int = 64):
 
 
 if __name__ == "__main__":
+    from repro.core.envcfg import enable_compile_cache
+    enable_compile_cache()
     run()

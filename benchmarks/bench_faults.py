@@ -307,4 +307,6 @@ def run():
 
 
 if __name__ == "__main__":
+    from repro.core.envcfg import enable_compile_cache
+    enable_compile_cache()
     run()

@@ -54,7 +54,13 @@ WINDOWS = 3             # timed windows per child; best-of damps CI noise
 
 
 def _child(shards: int) -> dict:
-    """Runs inside the subprocess (XLA_FLAGS already set by the parent)."""
+    """Runs inside the subprocess (XLA_FLAGS already set by the parent).
+
+    Refuses to run with fewer devices than shards: the mesh would clamp
+    to what is present (an accelerator host ignores the forced host
+    device count) and the record would claim a speedup nobody ran.
+    """
+    import jax
     import numpy as np
 
     from repro.core import ArchSpec, CamType, compile_fn
@@ -65,6 +71,10 @@ def _child(shards: int) -> dict:
         d = diff.norm(p=2, dim=-1)
         return d.topk(K, largest=False)
 
+    if jax.device_count() < shards:
+        raise RuntimeError(
+            f"{shards} shards requested but {jax.device_count()} "
+            f"{jax.default_backend()} device(s) present")
     rng = np.random.default_rng(0)
     gallery = rng.standard_normal((N_GALLERY, DIM)).astype(np.float32)
     example_q = rng.standard_normal((PLAN_BATCH, DIM)).astype(np.float32)
@@ -118,7 +128,6 @@ def _child(shards: int) -> dict:
         snap = srv.snapshot()
 
     wall = min(walls)       # best window: steady-state, CI-noise-damped
-    import jax
     return {
         "devices": jax.device_count(),
         "shards": plan.shards,
@@ -268,6 +277,8 @@ def main(argv=None) -> int:
     ap.add_argument("--run-child", type=int, default=None, metavar="SHARDS",
                     help=argparse.SUPPRESS)   # internal: in-process measure
     args = ap.parse_args(argv)
+    from repro.core.envcfg import enable_compile_cache
+    enable_compile_cache()
     if args.run_child is not None:
         print(_MARK + json.dumps(_child(args.run_child)))
         return 0

@@ -165,4 +165,6 @@ def run():
 
 
 if __name__ == "__main__":
+    from repro.core.envcfg import enable_compile_cache
+    enable_compile_cache()
     run()

@@ -169,10 +169,14 @@ def _child() -> dict:
 
 
 def _spawn_child(store_dir: str) -> dict:
+    # JAX's persistent compilation cache stays off in both children: a
+    # cache hit would make "cold" measure a lookup, and warm must owe its
+    # start-up to the plan store alone
     env = dict(os.environ,
                PYTHONPATH=os.path.join(ROOT, "src") + os.pathsep +
                os.environ.get("PYTHONPATH", ""),
-               REPRO_PLAN_STORE=store_dir)
+               REPRO_PLAN_STORE=store_dir,
+               JAX_ENABLE_COMPILATION_CACHE="false")
     out = subprocess.run(
         [sys.executable, "-m", "benchmarks.bench_tune", "--run-child"],
         env=env, capture_output=True, text=True, timeout=1800, cwd=ROOT)
@@ -206,12 +210,14 @@ def _cold_vs_warm() -> dict:
 
 def run() -> dict:
     banner("Tune — searched plans vs heuristics + plan-store warm start")
+    # the children first: an accelerator belongs to one process at a
+    # time, and the in-process sweep would hold it
+    cw = _cold_vs_warm()
     sweep = _sweep()
     print(table(sweep, cols=["shape", "n", "heuristic_ms", "tuned_ms",
                              "speedup", "trials"]))
     best = max(r["speedup"] for r in sweep)
 
-    cw = _cold_vs_warm()
     print(f"\ncold start : {cw['cold']['start_to_first_result_s']:.3f}s "
           f"({cw['cold']['trials']} trials)")
     print(f"warm start : {cw['warm']['start_to_first_result_s']:.3f}s "
@@ -243,6 +249,8 @@ def run() -> dict:
 
 def main(argv=None) -> int:
     argv = sys.argv[1:] if argv is None else argv
+    from repro.core.envcfg import enable_compile_cache
+    enable_compile_cache()
     if "--run-child" in argv:
         print(_MARK + json.dumps(_child()))
         return 0

@@ -111,4 +111,6 @@ def run(n_queries: int = 10_000, dim: int = 8192, n_classes: int = 10):
 
 
 if __name__ == "__main__":
+    from repro.core.envcfg import enable_compile_cache
+    enable_compile_cache()
     run()

@@ -84,4 +84,6 @@ def run(n_gallery: int = 180_000, dim: int = 1024, n_queries: int = 624,
 
 
 if __name__ == "__main__":
+    from repro.core.envcfg import enable_compile_cache
+    enable_compile_cache()
     run()

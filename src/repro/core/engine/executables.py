@@ -32,7 +32,6 @@ from typing import Any, Callable, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec
 
 from ...kernels import packing as kpack
@@ -249,15 +248,19 @@ def _tile_row_update(spec, packed: bool, placement=None):
 
 def _row_scatter_update(spec, packed: bool, interval: bool = False):
     """Row-update closure for the pallas executables, whose prepared
-    layout is the block-padded 2-D operand itself: encode/pack just the
-    touched rows and scatter them (padding lanes/columns stay zero)."""
+    layout is the block-padded 2-D operand itself — lane-major
+    ``(lanes, rows)`` when packed: encode/pack just the touched rows and
+    scatter them (padding lanes/columns stay zero)."""
     def relay(prepared, srcs, j):
         out = []
         for leaf, s in zip(prepared, srcs):
             rows = jnp.asarray(s)[j]
             if packed:
                 enc = kpack.pack_bits(_bits(rows, spec.metric))
-            elif interval:
+                enc = jnp.pad(enc, ((0, 0), (0, leaf.shape[0] - enc.shape[1])))
+                out.append(leaf.at[:, j].set(enc.T.astype(leaf.dtype)))
+                continue
+            if interval:
                 enc = rows.astype(jnp.float32)
             else:
                 enc = _encode(rows, spec.metric).astype(jnp.float32)
@@ -396,11 +399,11 @@ def _build_sharded_executable(spec: SimilaritySpec, batch: int, shards: int,
     def chunk_fn(q, pt):
         qt = _layout_queries(q, spec, packed)
         # PartitionSpec("data") applies prefix-wise to every pattern leaf
-        return shard_map(
+        return jax.shard_map(
             local_scan, mesh=mesh,
             in_specs=(PartitionSpec(), PartitionSpec("data")),
             out_specs=(PartitionSpec("data"), PartitionSpec("data")),
-            check_rep=False)(qt, pt)                          # (S, B, k)
+            check_vma=False)(qt, pt)                          # (S, B, k)
 
     sh = NamedSharding(mesh, PartitionSpec("data"))
     return prepare, jax.jit(chunk_fn), _tile_row_update(spec, packed,
@@ -441,44 +444,46 @@ def _build_pallas_executable(spec: SimilaritySpec, batch: int,
     behind the plan cache) instead of on every ``cam_topk`` call.  With
     ``packed=True`` the packed XOR+popcount kernel runs over uint32
     lanes (lane-blocked grid) instead of the float MXU decomposition —
-    candidates are bit-identical either way.
+    candidates are bit-identical either way.  Kernel blocks start from
+    the modelled subarray (``tile_rows`` x ``dims_per_tile``) rounded to
+    what the TPU tiling admits.
     """
-    from ...kernels import ops as kops
+    from ...kernels import cam_search as kcs
+    from ...kernels.ops import pad_to_blocks
 
     metric, k = spec.metric, spec.k
     phys_metric, to_logical, phys_largest = _metric_values(metric, spec.largest)
     n, dim = spec.n, spec.dim
     ternary = spec.care_arg is not None
     k_eff = min(k, n)
-    bn = max(8, min(spec.tile_rows, n))
-    bd = min(spec.dims_per_tile, dim)
-    bm = min(128, max(8, batch))
-    bl = max(1, min(kpack.lanes(bd), kpack.lanes(dim)))  # lane-unit tiling
+    bn, bm = kcs.lane_block(spec.tile_rows, n), kcs.row_block(128, batch)
+    bd = kcs.lane_block(min(spec.dims_per_tile, dim), dim)
+    bl = kcs.lane_block(kpack.lanes(min(spec.dims_per_tile, dim)),
+                        kpack.lanes(dim))
 
     def prepare(p, care=None):
-        if packed:
-            pp = kops.pad_to_blocks(
-                kpack.pack_bits(_bits(jnp.asarray(p), metric)), bn, bl)
+        if packed:      # lane-major (lanes, rows): see fused_topk_packed
+            pp = pad_to_blocks(
+                kpack.pack_bits(_bits(jnp.asarray(p), metric)).T, bl, bn)
             if care is None:
                 return (pp,)
-            cp = kops.pad_to_blocks(
-                kpack.pack_bits(jnp.asarray(care) != 0), bn, bl)
+            cp = pad_to_blocks(
+                kpack.pack_bits(jnp.asarray(care) != 0).T, bl, bn)
             return (pp, cp)
         pe = _encode(jnp.asarray(p), metric).astype(jnp.float32)
-        return (kops.pad_to_blocks(pe, bn, bd),)
+        return (pad_to_blocks(pe, bn, bd),)
 
     def chunk_fn(q, pp):
         if packed:
-            qp = kops.pad_to_blocks(
-                kpack.pack_bits(_bits(q, metric)), bm, bl)
-            v, i = kops.cam_topk_packed_prepadded(
+            qp = pad_to_blocks(kpack.pack_bits(_bits(q, metric)), bm, bl)
+            v, i = kcs.fused_topk_packed_pallas(
                 qp, pp[0], pp[1] if ternary else None, k=k_eff,
                 largest=phys_largest, n_valid=n, block_m=bm, block_n=bn,
                 block_l=bl)
         else:
             qe = _encode(q, metric).astype(jnp.float32)
-            qp = kops.pad_to_blocks(qe, bm, bd)
-            v, i = kops.cam_topk_prepadded(
+            qp = pad_to_blocks(qe, bm, bd)
+            v, i = kcs.fused_topk_pallas(
                 qp, pp[0], metric=phys_metric, k=k_eff,
                 largest=phys_largest, n_valid=n, block_m=bm, block_n=bn,
                 block_d=bd)
@@ -632,11 +637,11 @@ def _build_range_sharded_executable(spec: RangeSpec, batch: int, shards: int,
 
     def chunk_fn(q, pt):
         qt = _layout_queries(q, spec, packed)
-        return shard_map(
+        return jax.shard_map(
             local_scan, mesh=mesh,
             in_specs=(PartitionSpec(), PartitionSpec("data")),
             out_specs=PartitionSpec("data"),
-            check_rep=False)(qt, pt)                     # (S, B, tps*tr)
+            check_vma=False)(qt, pt)                     # (S, B, tps*tr)
 
     sh = NamedSharding(mesh, PartitionSpec("data"))
     return prepare, jax.jit(chunk_fn), _tile_row_update(spec, packed,
@@ -652,12 +657,13 @@ def _build_range_pallas_executable(spec: RangeSpec, batch: int):
     leaves it.  Unpacked operands only (the packed popcount path lives
     in the jnp executable).
     """
-    from ...kernels import ops as kops
+    from ...kernels import acam as kacam
+    from ...kernels.cam_search import lane_block, row_block
+    from ...kernels.ops import pad_to_blocks
 
     n, dim = spec.n, spec.dim
-    bn = max(8, min(spec.tile_rows, n))
-    bd = min(spec.dims_per_tile, dim)
-    bm = min(128, max(8, batch))
+    bn, bm = lane_block(spec.tile_rows, n), row_block(128, batch)
+    bd = lane_block(min(spec.dims_per_tile, dim), dim)
     interval = spec.mode == "interval"
     if not interval:
         phys_metric, _, _ = _metric_values(spec.metric, True)
@@ -667,22 +673,21 @@ def _build_range_pallas_executable(spec: RangeSpec, batch: int):
     def prepare(*pats):
         if interval:
             return tuple(
-                kops.pad_to_blocks(jnp.asarray(p).astype(jnp.float32),
-                                   bn, bd)
+                pad_to_blocks(jnp.asarray(p).astype(jnp.float32), bn, bd)
                 for p in pats)
         pe = _encode(jnp.asarray(pats[0]), spec.metric).astype(jnp.float32)
-        return (kops.pad_to_blocks(pe, bn, bd),)
+        return (pad_to_blocks(pe, bn, bd),)
 
     def chunk_fn(q, pp):
         if interval:
-            qp = kops.pad_to_blocks(q.astype(jnp.float32), bm, bd)
-            hit = kops.acam_match_prepadded(
+            qp = pad_to_blocks(q.astype(jnp.float32), bm, bd)
+            hit = kacam.acam_match_pallas(
                 qp, pp[0], pp[1], n_valid=n, block_m=bm, block_n=bn,
                 block_d=bd)
         else:
             qe = _encode(q, spec.metric).astype(jnp.float32)
-            qp = kops.pad_to_blocks(qe, bm, bd)
-            hit = kops.cam_range_match_prepadded(
+            qp = pad_to_blocks(qe, bm, bd)
+            hit = kacam.range_match_pallas(
                 qp, pp[0], metric=phys_metric, threshold=spec.threshold,
                 below=spec.below, to_logical=to_logical, dim=dim,
                 n_valid=n, block_m=bm, block_n=bn, block_d=bd)

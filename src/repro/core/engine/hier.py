@@ -54,7 +54,6 @@ from typing import Any, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec
 
 from ...launch.mesh import make_data_mesh
@@ -492,13 +491,13 @@ def _hier_probe_sharded(spec_h: HierarchicalSpec, packed: bool,
             kd, kg = run(qt_l, gather, bsz, budget)
             return to_logical(kd, float(fine.dim))[None], kg[None]
 
-        return shard_map(
+        return jax.shard_map(
             local, mesh=mesh,
             in_specs=(PartitionSpec(), PartitionSpec(),
                       PartitionSpec("data"), PartitionSpec("data"),
                       PartitionSpec()),
             out_specs=(PartitionSpec("data"), PartitionSpec("data")),
-            check_rep=False)(qt, ci, leaf, rid, cnt)         # (S, B, k)
+            check_vma=False)(qt, ci, leaf, rid, cnt)         # (S, B, k)
 
     return jax.jit(probe, static_argnums=(5, 6))
 

@@ -10,7 +10,9 @@ offending value, and what would have been accepted — at the *first*
 read, not after a plan half-built itself around a default.
 
 Unset variables always mean the documented default; the helpers never
-read anything but ``os.environ``.
+read anything but ``os.environ``.  :func:`enable_compile_cache` is the
+one entry-point helper that also sets JAX configuration: where JAX keeps
+its persistent compilation cache.
 """
 
 import os
@@ -18,8 +20,12 @@ from typing import Optional, Sequence, Tuple
 
 __all__ = [
     "env_flag", "env_int", "env_float", "env_choice", "env_gate",
-    "env_path",
+    "env_path", "enable_compile_cache",
 ]
+
+#: the repository root (``src/repro/core`` -> three levels up)
+_REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
 
 #: accepted spellings for boolean-ish flags (case-insensitive)
 _TRUE: Tuple[str, ...] = ("1", "true", "on", "yes")
@@ -127,3 +133,21 @@ def env_gate(name: str, auto: float) -> float:
         return float(v)
     except ValueError:
         raise _bad(name, raw, "'auto', 'off', or a number") from None
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Called by the entry points (``chip_smoke.py``, the benchmarks), never
+    by the library or the tests.  When ``JAX_COMPILATION_CACHE_DIR`` is
+    set this changes nothing — JAX reads the variable itself.  Otherwise
+    the cache goes to ``<repo>/.jax_cache``: a fixed path, because a
+    later process finds an entry only under the same directory.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+    path = os.path.join(_REPO_ROOT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path

@@ -18,6 +18,7 @@
 * `flash_attention` — online-softmax attention forward (the LM framework's
                       hot spot; §Perf cell B's TPU answer).
 
-All kernels are written for TPU (pl.pallas_call + BlockSpec VMEM tiling)
-and validated on CPU in interpret mode against the oracles.
+All kernels are written for TPU (pl.pallas_call + BlockSpec VMEM tiling),
+validated on CPU in interpret mode against the oracles, and compiled for
+a described TPU v5e chip by ``tests/test_tpu_compile.py``.
 """

@@ -40,8 +40,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .cam_search import METRIC_COEFFS, _term
-from .pallas_compat import CompilerParams as _CompilerParams
+from .cam_search import _partial_distance, resolve_interpret
 
 __all__ = ["acam_match_pallas", "range_match_pallas"]
 
@@ -99,7 +98,7 @@ def _interval_kernel(q_ref, lo_ref, hi_ref, o_ref, acc_ref, *, nd: int,
 def acam_match_pallas(queries: jax.Array, lo: jax.Array, hi: jax.Array, *,
                       block_m: int = 128, block_n: int = 128,
                       block_d: int = 128, n_valid: int | None = None,
-                      interpret: bool = True) -> jax.Array:
+                      interpret: bool | None = None) -> jax.Array:
     """(M, N) int8 interval-match matrix (1 = row matches the query).
 
     ``queries`` (M, D); ``lo``/``hi`` (N, D) per-row interval bounds.
@@ -129,9 +128,9 @@ def acam_match_pallas(queries: jax.Array, lo: jax.Array, hi: jax.Array, *,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, d: (i, j)),
         out_shape=jax.ShapeDtypeStruct((nm * bm, nn * bn), jnp.int8),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qp, lop, hip)
     return out[:m, :n]
 
@@ -142,21 +141,12 @@ def _range_kernel(q_ref, p_ref, o_ref, acc_ref, *, metric: str, nd: int,
     """Distance accumulation (MXU decomposition) + threshold at last d."""
     d = pl.program_id(2)
     j = pl.program_id(1)
-    alpha, beta, gamma, qk, pk = METRIC_COEFFS[metric]
 
     @pl.when(d == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[...].astype(jnp.float32)
-    p = p_ref[...].astype(jnp.float32)
-    part = alpha * jax.lax.dot_general(
-        q, p, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-    if beta:
-        part = part + beta * jnp.sum(_term(q, qk), axis=1, keepdims=True)
-    if gamma:
-        part = part + gamma * jnp.sum(_term(p, pk), axis=1)[None, :]
-    acc_ref[...] += part
+    acc_ref[...] += _partial_distance(q_ref[...], p_ref[...], metric)
 
     @pl.when(d == nd - 1)
     def _emit():
@@ -169,7 +159,7 @@ def range_match_pallas(queries: jax.Array, patterns: jax.Array, *,
                        to_logical: str = "identity", dim: int | None = None,
                        block_m: int = 128, block_n: int = 128,
                        block_d: int = 512, n_valid: int | None = None,
-                       interpret: bool = True) -> jax.Array:
+                       interpret: bool | None = None) -> jax.Array:
     """(M, N) int8 threshold-match matrix (TH sensing, ``dist <= tau``).
 
     ``metric`` is the *physical* metric (hamming / dot / eucl — the
@@ -200,8 +190,8 @@ def range_match_pallas(queries: jax.Array, patterns: jax.Array, *,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, d: (i, j)),
         out_shape=jax.ShapeDtypeStruct((nm * bm, nn * bn), jnp.int8),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qp, pp)
     return out[:m, :n]

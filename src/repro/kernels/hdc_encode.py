@@ -35,7 +35,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import CompilerParams as _CompilerParams
+from .cam_search import resolve_interpret
+
 
 __all__ = ["hdc_encode_pallas"]
 
@@ -69,7 +70,7 @@ def _encode_kernel(q_ref, k_ref, l_ref, o_ref, acc_ref, *, n_levels: int,
 def hdc_encode_pallas(level_idx: jax.Array, keys: jax.Array,
                       levels: jax.Array, *, block_m: int = 128,
                       block_f: int = 256, block_h: int = 256,
-                      interpret: bool = True) -> jax.Array:
+                      interpret: bool | None = None) -> jax.Array:
     """(M, H) bipolar encodings; operands must be block-aligned.
 
     ``level_idx`` (M, F) int32, ``keys`` (F, H) float32 bipolar (zero
@@ -95,8 +96,8 @@ def hdc_encode_pallas(level_idx: jax.Array, keys: jax.Array,
         out_specs=pl.BlockSpec((bm, bh), lambda i, hh, f: (i, hh)),
         out_shape=jax.ShapeDtypeStruct((nm * bm, nh * bh), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bh), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(level_idx, keys, levels)
     return out[:m, :h]

@@ -5,9 +5,10 @@ to float tolerance (analog).  ``cam_topk`` pads inputs to block multiples
 on every call so the kernels only ever see aligned shapes; the search-plan
 engine (`repro.core.engine`) instead hoists that padding behind its plan
 cache — patterns are laid out once per stored array via
-:func:`pad_to_blocks` and streamed through :func:`cam_topk_prepadded`.
-`interpret` defaults to True off-TPU (this container is CPU-only; on a
-real TPU backend the same code path compiles through Mosaic).
+:func:`pad_to_blocks` and streamed straight into the kernels.
+``interpret=None`` (the default everywhere) compiles through Mosaic on a
+TPU backend and runs the kernel interpreter elsewhere
+(``cam_search.resolve_interpret``).
 """
 
 from __future__ import annotations
@@ -21,18 +22,12 @@ import jax.numpy as jnp
 from . import ref as kref
 from .acam import acam_match_pallas, range_match_pallas
 from .cam_search import (distance_pallas, fused_topk_pallas,
-                         fused_topk_packed_pallas)
+                         fused_topk_packed_pallas, lane_block, row_block)
 from .hdc_encode import hdc_encode_pallas
 
-__all__ = ["cam_topk", "cam_topk_prepadded", "cam_topk_packed",
-           "cam_topk_packed_prepadded", "pad_to_blocks", "cam_exact",
-           "cam_range", "acam_match", "acam_match_prepadded",
-           "cam_range_match", "cam_range_match_prepadded",
+__all__ = ["cam_topk", "cam_topk_packed", "pad_to_blocks", "cam_exact",
+           "cam_range", "acam_match", "cam_range_match",
            "hdc_bind", "hdc_bundle", "hdc_permute", "hdc_encode"]
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def pad_to_blocks(x: jax.Array, mult0: int, mult1: int) -> jax.Array:
@@ -42,65 +37,6 @@ def pad_to_blocks(x: jax.Array, mult0: int, mult1: int) -> jax.Array:
     if p0 or p1:
         x = jnp.pad(x, ((0, p0), (0, p1)))
     return x
-
-
-_pad_to = pad_to_blocks   # backwards-compatible internal alias
-
-
-@functools.partial(jax.jit, static_argnames=("metric", "k", "largest",
-                                             "n_valid", "block_m", "block_n",
-                                             "block_d", "interpret"))
-def cam_topk_prepadded(qp: jax.Array, pp: jax.Array, *, metric: str, k: int,
-                       largest: bool, n_valid: int, block_m: int,
-                       block_n: int, block_d: int,
-                       interpret: Optional[bool] = None
-                       ) -> Tuple[jax.Array, jax.Array]:
-    """Kernel launch + candidate merge for block-aligned operands.
-
-    The hot path of the search-plan engine: operand padding already
-    happened (once, behind the plan cache) so each micro-batch chunk goes
-    straight to the fused kernel.  ``k`` must already be clamped to
-    ``n_valid``.  Returns padded-row results; callers slice to valid rows.
-    """
-    if interpret is None:
-        interpret = not _on_tpu()
-    vals, idx = fused_topk_pallas(qp, pp, metric=metric, k=k,
-                                  largest=largest, block_m=block_m,
-                                  block_n=block_n, block_d=block_d,
-                                  n_valid=n_valid, interpret=interpret)
-    # final candidate merge (stable: block-major order == ascending global
-    # row index, so ties resolve to the lower index, matching ref)
-    key = vals if largest else -vals
-    _, sel = jax.lax.top_k(key, k)
-    return (jnp.take_along_axis(vals, sel, axis=-1),
-            jnp.take_along_axis(idx, sel, axis=-1))
-
-
-@functools.partial(jax.jit, static_argnames=("k", "largest", "n_valid",
-                                             "block_m", "block_n", "block_l",
-                                             "interpret"))
-def cam_topk_packed_prepadded(qp: jax.Array, pp: jax.Array,
-                              cp: Optional[jax.Array] = None, *, k: int,
-                              largest: bool, n_valid: int, block_m: int,
-                              block_n: int, block_l: int,
-                              interpret: Optional[bool] = None
-                              ) -> Tuple[jax.Array, jax.Array]:
-    """Packed-lane analogue of :func:`cam_topk_prepadded`.
-
-    Operands are uint32 lane arrays already padded to block multiples
-    (zero lanes match in both operands, so padding never contributes a
-    mismatch).  ``cp`` is the optional packed per-pattern TCAM care
-    mask.  Same final stable candidate merge as the float path.
-    """
-    if interpret is None:
-        interpret = not _on_tpu()
-    vals, idx = fused_topk_packed_pallas(
-        qp, pp, cp, k=k, largest=largest, block_m=block_m, block_n=block_n,
-        block_l=block_l, n_valid=n_valid, interpret=interpret)
-    key = vals if largest else -vals
-    _, sel = jax.lax.top_k(key, k)
-    return (jnp.take_along_axis(vals, sel, axis=-1),
-            jnp.take_along_axis(idx, sel, axis=-1))
 
 
 @functools.partial(jax.jit, static_argnames=("k", "largest", "tile_rows",
@@ -122,14 +58,14 @@ def cam_topk_packed(qbits: jax.Array, pbits: jax.Array,
     m, L = qbits.shape
     n = pbits.shape[0]
     k_eff = min(k, n)
-    bn = max(8, min(tile_rows, n))
-    bl = min(lanes_per_tile, L)
-    bm = min(block_m, max(8, m))
+    bn = lane_block(tile_rows, n)
+    bl = lane_block(lanes_per_tile, L)
+    bm = row_block(block_m, m)
     qp = pad_to_blocks(qbits, bm, bl)
-    pp = pad_to_blocks(pbits, bn, bl)
-    cp = None if care is None else pad_to_blocks(care, bn, bl)
-    vals, idx = cam_topk_packed_prepadded(
-        qp, pp, cp, k=k_eff, largest=largest, n_valid=n, block_m=bm,
+    ppt = pad_to_blocks(pbits.T, bl, bn)
+    cpt = None if care is None else pad_to_blocks(care.T, bl, bn)
+    vals, idx = fused_topk_packed_pallas(
+        qp, ppt, cpt, k=k_eff, largest=largest, n_valid=n, block_m=bm,
         block_n=bn, block_l=bl, interpret=interpret)
     return kref.pad_candidates(vals[:m], idx[:m], k, largest)
 
@@ -150,15 +86,15 @@ def cam_topk(queries: jax.Array, patterns: jax.Array, *, metric: str, k: int,
     m, dim = queries.shape
     n = patterns.shape[0]
     k_eff = min(k, n)
-    bn = max(8, min(tile_rows, n))
-    bd = min(dims_per_tile, dim)
-    bm = min(block_m, max(8, m))
+    bn = lane_block(tile_rows, n)
+    bd = lane_block(dims_per_tile, dim)
+    bm = row_block(block_m, m)
     qp = pad_to_blocks(queries.astype(jnp.float32), bm, bd)
     pp = pad_to_blocks(patterns.astype(jnp.float32), bn, bd)
-    vals, idx = cam_topk_prepadded(qp, pp, metric=metric, k=k_eff,
-                                   largest=largest, n_valid=n, block_m=bm,
-                                   block_n=bn, block_d=bd,
-                                   interpret=interpret)
+    vals, idx = fused_topk_pallas(qp, pp, metric=metric, k=k_eff,
+                                  largest=largest, n_valid=n, block_m=bm,
+                                  block_n=bn, block_d=bd,
+                                  interpret=interpret)
     # k > N: pad with the shared losing sentinels (same helper the engine
     # and tiled reference use, so every path emits identical pad content)
     return kref.pad_candidates(vals[:m], idx[:m], k, largest)
@@ -189,13 +125,11 @@ def hdc_encode(level_idx: jax.Array, keys: jax.Array, levels: jax.Array, *,
     kernel, and slices the valid block.  Bit-identical to
     :func:`ref.hdc_encode` (integer sums, sign tie -> +1).
     """
-    if interpret is None:
-        interpret = not _on_tpu()
     m, f = level_idx.shape
     h = keys.shape[1]
-    bm = min(block_m, max(8, m))
-    bf = min(block_f, f)
-    bh = min(block_h, h)
+    bm = row_block(block_m, m)
+    bf = lane_block(block_f, f)
+    bh = lane_block(block_h, h)
     qp = pad_to_blocks(level_idx.astype(jnp.int32), bm, bf)
     kp = pad_to_blocks(keys.astype(jnp.float32), bf, bh)
     lp = jnp.pad(levels.astype(jnp.float32), ((0, 0), (0, (-h) % bh)))
@@ -207,13 +141,13 @@ def hdc_encode(level_idx: jax.Array, keys: jax.Array, levels: jax.Array, *,
 @functools.partial(jax.jit, static_argnames=("metric", "interpret"))
 def cam_distances(queries: jax.Array, patterns: jax.Array, *, metric: str,
                   interpret: Optional[bool] = None) -> jax.Array:
-    if interpret is None:
-        interpret = not _on_tpu()
     m, dim = queries.shape
     n = patterns.shape[0]
-    qp = _pad_to(queries.astype(jnp.float32), 8, 128)
-    pp = _pad_to(patterns.astype(jnp.float32), 8, 128)
-    d = distance_pallas(qp, pp, metric=metric, interpret=interpret)
+    bm, bn, bd = row_block(128, m), lane_block(128, n), lane_block(512, dim)
+    qp = pad_to_blocks(queries.astype(jnp.float32), bm, bd)
+    pp = pad_to_blocks(patterns.astype(jnp.float32), bn, bd)
+    d = distance_pallas(qp, pp, metric=metric, block_m=bm, block_n=bn,
+                        block_d=bd, interpret=interpret)
     return d[:m, :n]
 
 
@@ -236,25 +170,6 @@ def cam_range(queries: jax.Array, patterns: jax.Array, threshold: float, *,
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.jit, static_argnames=("n_valid", "block_m", "block_n",
-                                             "block_d", "interpret"))
-def acam_match_prepadded(qp: jax.Array, lop: jax.Array, hip: jax.Array, *,
-                         n_valid: int, block_m: int, block_n: int,
-                         block_d: int, interpret: Optional[bool] = None
-                         ) -> jax.Array:
-    """Interval-match kernel launch for block-aligned operands.
-
-    The hot path of the engine's interval ``RangePlan`` on the pallas
-    backend: ``lo``/``hi`` were padded once behind the plan cache.
-    Returns the padded ``(M_pad, N_pad)`` int8 matrix; callers slice.
-    """
-    if interpret is None:
-        interpret = not _on_tpu()
-    return acam_match_pallas(qp, lop, hip, block_m=block_m, block_n=block_n,
-                             block_d=block_d, n_valid=n_valid,
-                             interpret=interpret)
-
-
 @jax.jit
 def acam_match(queries: jax.Array, lo: jax.Array, hi: jax.Array) -> jax.Array:
     """(M, N) boolean aCAM interval match via the fused Pallas kernel.
@@ -268,26 +183,8 @@ def acam_match(queries: jax.Array, lo: jax.Array, hi: jax.Array) -> jax.Array:
     n = lo.shape[0]
     out = acam_match_pallas(queries.astype(jnp.float32),
                             lo.astype(jnp.float32), hi.astype(jnp.float32),
-                            n_valid=n, interpret=not _on_tpu())
+                            n_valid=n)
     return out[:m, :n] != 0
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "metric", "threshold", "below", "to_logical", "dim", "n_valid", "block_m",
-    "block_n", "block_d", "interpret"))
-def cam_range_match_prepadded(qp: jax.Array, pp: jax.Array, *, metric: str,
-                              threshold: float, below: bool, to_logical: str,
-                              dim: int, n_valid: int, block_m: int,
-                              block_n: int, block_d: int,
-                              interpret: Optional[bool] = None) -> jax.Array:
-    """Fused threshold-match launch for block-aligned operands (int8)."""
-    if interpret is None:
-        interpret = not _on_tpu()
-    return range_match_pallas(qp, pp, metric=metric, threshold=threshold,
-                              below=below, to_logical=to_logical, dim=dim,
-                              block_m=block_m, block_n=block_n,
-                              block_d=block_d, n_valid=n_valid,
-                              interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("metric", "threshold", "below",
@@ -303,8 +200,6 @@ def cam_range_match(queries: jax.Array, patterns: jax.Array, *, metric: str,
     for the TH sensing mode.  Physical-metric contract matches
     :func:`ref.cam_range` on hamming/dot/eucl.
     """
-    if interpret is None:
-        interpret = not _on_tpu()
     out = range_match_pallas(queries.astype(jnp.float32),
                              patterns.astype(jnp.float32), metric=metric,
                              threshold=threshold, below=below,

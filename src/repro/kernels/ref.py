@@ -42,6 +42,11 @@ __all__ = ["distances", "packed_distances", "ternary_distances",
            "hdc_encode"]
 
 
+#: float32 contractions at full precision: a TPU's default rounds float32
+#: operands to bfloat16, which no float32 distance contract allows
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
 def distances(queries: jax.Array, patterns: jax.Array, metric: str) -> jax.Array:
     """(M, N) distance/similarity matrix."""
     q = queries.astype(jnp.float32)
@@ -50,16 +55,16 @@ def distances(queries: jax.Array, patterns: jax.Array, metric: str) -> jax.Array
         # mismatch count; inputs {0,1}
         return (q[:, None, :] != p[None, :, :]).sum(-1).astype(jnp.float32)
     if metric == "dot":
-        return q @ p.T
+        return jnp.matmul(q, p.T, precision=_HIGHEST)
     if metric == "eucl":
         # squared L2 via expansion (matches tiled partial-sum accumulation)
         qq = (q * q).sum(-1, keepdims=True)
         pp = (p * p).sum(-1)
-        return qq + pp[None, :] - 2.0 * (q @ p.T)
+        return qq + pp[None, :] - 2.0 * jnp.matmul(q, p.T, precision=_HIGHEST)
     if metric == "cos":
         qn = q / jnp.maximum(jnp.linalg.norm(q, axis=-1, keepdims=True), 1e-12)
         pn = p / jnp.maximum(jnp.linalg.norm(p, axis=-1, keepdims=True), 1e-12)
-        return qn @ pn.T
+        return jnp.matmul(qn, pn.T, precision=_HIGHEST)
     raise ValueError(f"unknown metric {metric!r}")
 
 
@@ -253,11 +258,12 @@ def tile_distance(q_t: jax.Array, p_t: jax.Array, metric: str) -> jax.Array:
     if metric == "hamming":
         return (q_t[:, None, :] != p_t[None, :, :]).sum(-1).astype(jnp.float32)
     if metric == "dot":
-        return q_t @ p_t.T
+        return jnp.matmul(q_t, p_t.T, precision=_HIGHEST)
     if metric == "eucl":
         qq = (q_t * q_t).sum(-1, keepdims=True)
         ppv = (p_t * p_t).sum(-1)
-        return qq + ppv[None, :] - 2.0 * (q_t @ p_t.T)
+        return qq + ppv[None, :] - 2.0 * jnp.matmul(q_t, p_t.T,
+                                                    precision=_HIGHEST)
     raise ValueError(f"tiled path does not support metric {metric!r}")
 
 

@@ -135,8 +135,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool = False,
     mem = compiled.memory_analysis()
     print(f"[{arch} x {shape_name} x {mesh_name}] memory_analysis:", mem)
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):      # older jax: one dict per partition
-        ca = ca[0] if ca else {}
     print(f"[{arch} x {shape_name} x {mesh_name}] cost_analysis flops:",
           ca.get("flops"), "bytes:", ca.get("bytes accessed"))
 

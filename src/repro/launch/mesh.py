@@ -46,14 +46,8 @@ def forced_host_devices_env(n: int, env: Optional[Dict[str, str]] = None
 
 
 def _mk(shape, axes) -> Mesh:
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(
-                shape, axes, axis_types=(axis_type.Auto,) * len(axes))
-        except TypeError:                      # no axis_types kwarg yet
-            pass
-    return jax.make_mesh(shape, axes)          # older jax: no AxisType
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

@@ -149,10 +149,6 @@ def moe_ffn(p: Params, x: jax.Array, cfg: ModelConfig,
           and rules.model_size() > 1 and e % rules.model_size() == 0)
     if ep:
         from jax.sharding import PartitionSpec as P
-        try:
-            from jax import shard_map as _shard_map
-        except ImportError:                     # pragma: no cover
-            from jax.experimental.shard_map import shard_map as _shard_map
         mesh = rules.mesh
         maxis = rules.model_axis
         bd = rules.batch_axes
@@ -179,7 +175,7 @@ def moe_ffn(p: Params, x: jax.Array, cfg: ModelConfig,
         batch_spec = (bd if len(bd) > 1 else bd[0]) if bd else None
         bspec = P(batch_spec, None, None)
         out_spec = P(batch_spec, maxis, None) if scatter else bspec
-        y = _shard_map(
+        y = jax.shard_map(
             body, mesh=mesh,
             in_specs=(bspec, P(None, None), P(maxis, None, None),
                       P(maxis, None, None), P(maxis, None, None)),

@@ -132,3 +132,50 @@ def test_distance_pallas_matches(rng):
     d1 = np.asarray(ops.cam_distances(q, p, metric="eucl"))
     d2 = np.asarray(ref.distances(q, p, "eucl"))
     np.testing.assert_allclose(d1, d2, atol=1e-3)
+
+
+# The fused kernels keep each row block's running top-k in a lane-dense
+# (rows, 128 * ceil(k / 128)) output slab.  These shapes put k inside
+# and past one slab, and run every query through a single row block
+# (m <= block_m) as well as several.
+SLAB_SHAPES = [(1, 300, 40, 7), (128, 700, 96, 10), (5, 300, 40, 130),
+               (130, 1000, 64, 129)]
+
+
+@pytest.mark.parametrize("metric", ["hamming", "dot", "eucl"])
+@pytest.mark.parametrize("m,n,d,k", SLAB_SHAPES)
+def test_pallas_topk_lane_slab_matches_oracle(metric, m, n, d, k, rng):
+    q, p = _data(rng, metric, m, n, d)
+    largest = metric == "dot"
+    v1, i1 = ops.cam_topk(q, p, metric=metric, k=k, largest=largest,
+                          tile_rows=128, dims_per_tile=128)
+    v2, i2 = ref.cam_topk(q, p, metric=metric, k=k, largest=largest)
+    np.testing.assert_array_equal(np.asarray(i1), np.asarray(i2))
+    np.testing.assert_allclose(np.asarray(v1), np.asarray(v2), atol=1e-3)
+
+
+@pytest.mark.parametrize("m,n,d,k", SLAB_SHAPES)
+def test_pallas_packed_topk_lane_slab_matches_float_path(m, n, d, k, rng):
+    from repro.kernels import packing as kpack
+
+    q, p = _data(rng, "hamming", m, n, d)
+    fv, fi = ops.cam_topk(q, p, metric="hamming", k=k, largest=False,
+                          tile_rows=128, dims_per_tile=128)
+    pv, pi = ops.cam_topk_packed(kpack.pack_bits(q), kpack.pack_bits(p),
+                                 k=k, largest=False, tile_rows=128,
+                                 lanes_per_tile=128)
+    np.testing.assert_array_equal(np.asarray(fi), np.asarray(pi))
+    np.testing.assert_array_equal(np.asarray(fv), np.asarray(pv))
+
+
+def test_pallas_topk_ties_resolve_to_lower_index():
+    """Duplicate pattern rows in different blocks: the running fold
+    must keep the lower global index first, as a stable merge does."""
+    p = np.zeros((600, 16), np.float32)
+    p[[5, 130, 260, 599]] = 1.0               # four exact ties, 3 blocks
+    q = np.ones((3, 16), np.float32)
+    v, i = ops.cam_topk(jnp.asarray(q), jnp.asarray(p), metric="eucl",
+                        k=6, largest=False, tile_rows=128,
+                        dims_per_tile=128)
+    assert np.asarray(i)[:, :4].tolist() == [[5, 130, 260, 599]] * 3
+    assert np.asarray(i)[:, 4:].tolist() == [[0, 1]] * 3

@@ -30,8 +30,6 @@ def _compiled():
 def test_parser_reweights_scan_bodies():
     compiled = _compiled()
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):      # older jax: one dict per partition
-        ca = ca[0]
     rep = rl.analyze_compiled(compiled, n_devices=1)
 
     per_iter = 2 * M * K * K
