@@ -1,0 +1,8 @@
+"""Device idle share of the traced window."""
+
+
+def idle_pct(ctx):
+    t = ctx.trace
+    if t is None or not t.window_s:
+        return None
+    return 100.0 * (1.0 - t.busy_s / t.window_s)
