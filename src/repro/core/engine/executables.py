@@ -39,6 +39,25 @@ from ...kernels import ref as kref
 from ...launch.mesh import make_data_mesh
 from .spec import (RangeSpec, SimilaritySpec, _bits, _encode, _metric_values)
 
+#: the names the chunk executables are jitted under: the profiler names
+#: each XLA module ``jit_<name>``, and readers of device time look the
+#: search executable up by the shared ``chunk_fn`` part
+SEARCH_SCAN_CHUNK = "search_scan_chunk_fn"
+SEARCH_TINY_CHUNK = "search_tiny_chunk_fn"
+SEARCH_SHARDED_CHUNK = "search_sharded_chunk_fn"
+SEARCH_PALLAS_CHUNK = "search_pallas_chunk_fn"
+RANGE_SCAN_CHUNK = "range_scan_chunk_fn"
+RANGE_TINY_CHUNK = "range_tiny_chunk_fn"
+RANGE_SHARDED_CHUNK = "range_sharded_chunk_fn"
+RANGE_PALLAS_CHUNK = "range_pallas_chunk_fn"
+
+
+def _named_jit(fn: Callable, name: str):
+    """``jax.jit`` of ``fn`` under ``name``, whatever ``fn`` is called
+    in the source."""
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn)
+
 
 def _tile_rows_block(arr: jax.Array, tiles: jax.Array, tr: int,
                      n: int) -> jax.Array:
@@ -118,25 +137,28 @@ def _tile_tournament(spec: SimilaritySpec, col_dist: Callable,
             qc = xs[0]                  # horizontal merge, oracle arithmetic
             return acc + col_dist(qc, xs[1:]), None
 
-        dist, _ = jax.lax.scan(
-            col_step, jnp.zeros((batch, tr), jnp.float32), (qt, *pr),
-            unroll=min(unroll, qt.shape[0]))
-        gidx = roff + jnp.arange(tr, dtype=jnp.int32)
-        dist = jnp.where(gidx[None, :] < n, dist, lose)      # ragged rows
-        key = dist if phys_largest else -dist
-        _, idx = jax.lax.top_k(key, kk)
-        v = jnp.take_along_axis(dist, idx, axis=-1)
-        i = idx.astype(jnp.int32) + roff
-        i = jnp.where(i < n_phys, i, 2 ** 30)
-        return kref.pad_candidates(v, i, k, phys_largest)
+        with jax.named_scope("cam.distances"):
+            dist, _ = jax.lax.scan(
+                col_step, jnp.zeros((batch, tr), jnp.float32), (qt, *pr),
+                unroll=min(unroll, qt.shape[0]))
+        with jax.named_scope("cam.tile_topk"):
+            gidx = roff + jnp.arange(tr, dtype=jnp.int32)
+            dist = jnp.where(gidx[None, :] < n, dist, lose)  # ragged rows
+            key = dist if phys_largest else -dist
+            _, idx = jax.lax.top_k(key, kk)
+            v = jnp.take_along_axis(dist, idx, axis=-1)
+            i = idx.astype(jnp.int32) + roff
+            i = jnp.where(i < n_phys, i, 2 ** 30)
+            return kref.pad_candidates(v, i, k, phys_largest)
 
     def scan(qt, pt, roffs):
         def row_step(carry, xs):
             cv, ci = carry                                   # vertical merge
             tiles, roff = xs
             v, i = tile_topk(qt, tiles, roff)
-            return kref.merge_topk(cv, ci, v, i, k=k,
-                                   largest=phys_largest), None
+            with jax.named_scope("cam.merge_topk"):
+                return kref.merge_topk(cv, ci, v, i, k=k,
+                                       largest=phys_largest), None
 
         # tile 0 seeds the tournament (its padded-slot indices are real
         # column positions, which the interpreter also reports), remaining
@@ -285,7 +307,8 @@ def _row_scatter_update(spec, packed: bool, interval: bool = False):
 
 
 def _build_scan_executable(spec: SimilaritySpec, batch: int,
-                           packed: bool = False, unroll: int = 1):
+                           packed: bool = False, unroll: int = 1,
+                           name: str = SEARCH_SCAN_CHUNK):
     """(prepare_patterns, chunk_fn, row_update) for the jnp
     (reference-tiled) backend.
 
@@ -310,7 +333,8 @@ def _build_scan_executable(spec: SimilaritySpec, batch: int,
         v, i = scan(qt, pt, roffs)
         return to_logical(v, float(dim)), i
 
-    return jax.jit(prepare), jax.jit(chunk_fn), _tile_row_update(spec, packed)
+    return (jax.jit(prepare), _named_jit(chunk_fn, name),
+            _tile_row_update(spec, packed))
 
 
 def _dense_spec(spec):
@@ -340,7 +364,7 @@ def _build_tiny_executable(spec: SimilaritySpec, batch: int,
     semantics (see :func:`_dense_spec`).
     """
     return _build_scan_executable(_dense_spec(spec), batch, packed=packed,
-                                  unroll=unroll)
+                                  unroll=unroll, name=SEARCH_TINY_CHUNK)
 
 
 def _build_sharded_executable(spec: SimilaritySpec, batch: int, shards: int,
@@ -406,8 +430,8 @@ def _build_sharded_executable(spec: SimilaritySpec, batch: int, shards: int,
             check_vma=False)(qt, pt)                          # (S, B, k)
 
     sh = NamedSharding(mesh, PartitionSpec("data"))
-    return prepare, jax.jit(chunk_fn), _tile_row_update(spec, packed,
-                                                        placement=sh)
+    return prepare, _named_jit(chunk_fn, SEARCH_SHARDED_CHUNK), \
+        _tile_row_update(spec, packed, placement=sh)
 
 
 def merge_shard_candidates(values: Any, indices: Any, *, k: int,
@@ -491,8 +515,8 @@ def _build_pallas_executable(spec: SimilaritySpec, batch: int,
         v, i = kref.pad_candidates(v[:b], i[:b], k, phys_largest)
         return to_logical(v, float(dim)), i
 
-    return jax.jit(prepare), jax.jit(chunk_fn), _row_scatter_update(spec,
-                                                                    packed)
+    return (jax.jit(prepare), _named_jit(chunk_fn, SEARCH_PALLAS_CHUNK),
+            _row_scatter_update(spec, packed))
 
 
 # ---------------------------------------------------------------------------
@@ -579,7 +603,8 @@ def _lay_range_patterns(pats, spec: RangeSpec, gr_total: int,
 
 
 def _build_range_scan_executable(spec: RangeSpec, batch: int,
-                                 packed: bool = False, unroll: int = 1):
+                                 packed: bool = False, unroll: int = 1,
+                                 name: str = RANGE_SCAN_CHUNK):
     """(prepare, chunk_fn, row_update) for the jnp range path: chunk_fn
     returns the ``(batch, grid_rows * tile_rows)`` boolean match block."""
     gr = spec.grid_rows
@@ -595,7 +620,8 @@ def _build_range_scan_executable(spec: RangeSpec, batch: int,
         hit = compare(d)
         return hit.transpose(1, 0, 2).reshape(q.shape[0], -1)
 
-    return jax.jit(prepare), jax.jit(chunk_fn), _tile_row_update(spec, packed)
+    return (jax.jit(prepare), _named_jit(chunk_fn, name),
+            _tile_row_update(spec, packed))
 
 
 def _build_tiny_range_executable(spec: RangeSpec, batch: int,
@@ -604,7 +630,8 @@ def _build_tiny_range_executable(spec: RangeSpec, batch: int,
     small-program case) — the range twin of
     :func:`_build_tiny_executable`."""
     return _build_range_scan_executable(_dense_spec(spec), batch,
-                                        packed=packed, unroll=unroll)
+                                        packed=packed, unroll=unroll,
+                                        name=RANGE_TINY_CHUNK)
 
 
 def _build_range_sharded_executable(spec: RangeSpec, batch: int, shards: int,
@@ -644,8 +671,8 @@ def _build_range_sharded_executable(spec: RangeSpec, batch: int, shards: int,
             check_vma=False)(qt, pt)                     # (S, B, tps*tr)
 
     sh = NamedSharding(mesh, PartitionSpec("data"))
-    return prepare, jax.jit(chunk_fn), _tile_row_update(spec, packed,
-                                                        placement=sh)
+    return prepare, _named_jit(chunk_fn, RANGE_SHARDED_CHUNK), \
+        _tile_row_update(spec, packed, placement=sh)
 
 
 def _build_range_pallas_executable(spec: RangeSpec, batch: int):
@@ -693,5 +720,5 @@ def _build_range_pallas_executable(spec: RangeSpec, batch: int):
                 n_valid=n, block_m=bm, block_n=bn, block_d=bd)
         return hit[:q.shape[0]] != 0
 
-    return jax.jit(prepare), jax.jit(chunk_fn), _row_scatter_update(
-        spec, packed=False, interval=interval)
+    return (jax.jit(prepare), _named_jit(chunk_fn, RANGE_PALLAS_CHUNK),
+            _row_scatter_update(spec, packed=False, interval=interval))

@@ -10,6 +10,12 @@ nesting, complete events (``ph: X``) for cross-thread request windows,
 instants (``ph: i``) for point occurrences, and ``M`` metadata rows
 naming processes and threads.
 
+While recording, every same-thread span also opens a
+``jax.profiler.TraceAnnotation`` of the same name, so a profile taken
+with ``jax.profiler.trace`` shows the program's spans on the device
+trace's own clock, and each garbage collection is recorded as one
+``host.gc`` span.
+
 Design constraints, in order:
 
 * **Disabled must cost ~nothing.**  Every call site sits on a serving
@@ -37,13 +43,14 @@ buffer on demand.
 from __future__ import annotations
 
 import atexit
+import gc
 import json
 import threading
 import time
 from collections import deque
 from typing import Any, Dict, List, Optional
 
-from ..core.envcfg import env_choice, env_int, env_path
+from ..core.envcfg import env_int, env_path
 
 __all__ = [
     "TraceRecorder", "tracer", "enable", "stop", "configure_from_env",
@@ -53,11 +60,7 @@ __all__ = [
 
 #: stable pid assignment per component so cross-component traces line
 #: up identically run to run
-_PIDS = {"engine": 1, "serving": 2, "gateway": 3}
-
-
-def _clock_ns() -> int:
-    return time.perf_counter_ns()
+_PIDS = {"engine": 1, "serving": 2, "gateway": 3, "host": 4}
 
 
 class TraceRecorder:
@@ -67,20 +70,23 @@ class TraceRecorder:
     disabled fast path is one attribute load and a branch.
     """
 
-    def __init__(self, capacity: int = 65536, clock: str = "perf"):
+    def __init__(self, capacity: int = 65536):
         self.enabled = False
         self.capacity = int(capacity)
-        self.clock = clock
-        self._clock_ns = (time.monotonic_ns if clock == "mono"
-                          else time.perf_counter_ns)
         self._events: deque = deque(maxlen=self.capacity)
         self._thread_names: Dict[int, str] = {}
         self._names_lock = threading.Lock()
         self._atexit_path: Optional[str] = None
+        #: ``jax.profiler`` once recording has started (imported then,
+        #: so the package stays a leaf and tracing off never loads it)
+        self._profiler: Any = None
+        self._gc_t0: Optional[int] = None
+        self._gc_ann: Any = None
 
     # -- hot path -------------------------------------------------------
-    def now(self) -> int:
-        return self._clock_ns()
+    @staticmethod
+    def now() -> int:
+        return time.perf_counter_ns()
 
     def emit(self, ph: str, name: str, pid: str, ts: int,
              dur: Optional[int] = None,
@@ -100,12 +106,54 @@ class TraceRecorder:
                          # when the origin thread opened the handle
         self._events.append((ph, name, pid, t, ts, dur, args))
 
+    def annotation(self, name: str, args: Optional[Dict[str, Any]]):
+        """An entered ``jax.profiler.TraceAnnotation`` mirroring a span
+        into the profiler's trace (a no-op unless a profile is being
+        taken)."""
+        ann = self._profiler.TraceAnnotation(name, **(args or {}))
+        ann.__enter__()
+        return ann
+
+    def _on_gc(self, phase: str, info: Dict[str, int]) -> None:
+        """``gc.callbacks`` hook: one ``host.gc`` ``X`` event per
+        collection, emitted at its end on the collecting thread and
+        mirrored into the profiler.  The event carries its tid, so the
+        hook never takes ``_names_lock``, which the collecting thread
+        may be holding."""
+        if phase == "start":
+            self._gc_t0 = self.now()
+            self._gc_ann = self.annotation(
+                "host.gc", {"generation": info["generation"]})
+            return
+        t0, ann = self._gc_t0, self._gc_ann
+        self._gc_t0 = self._gc_ann = None
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        if t0 is not None:
+            self.emit("X", "host.gc", "host", t0, dur=self.now() - t0,
+                      args={"generation": info["generation"],
+                            "collected": info["collected"]},
+                      tid=threading.get_ident())
+
     # -- control --------------------------------------------------------
     def start(self) -> None:
+        if self._profiler is None:
+            import jax.profiler
+            self._profiler = jax.profiler
+        if self._on_gc not in gc.callbacks:
+            gc.callbacks.append(self._on_gc)
         self.enabled = True
 
     def stop(self) -> None:
         self.enabled = False
+        if self._on_gc in gc.callbacks:
+            gc.callbacks.remove(self._on_gc)
+
+    def resize(self, capacity: int) -> None:
+        """Change the ring's capacity, keeping the newest events."""
+        if capacity != self.capacity:
+            self.capacity = int(capacity)
+            self._events = deque(self._events, maxlen=self.capacity)
 
     def clear(self) -> None:
         self._events.clear()
@@ -202,9 +250,10 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """Same-thread duration span (``B`` on enter, ``E`` on exit)."""
+    """Same-thread duration span (``B`` on enter, ``E`` on exit), with
+    a profiler annotation of the same name around it."""
 
-    __slots__ = ("name", "pid", "args")
+    __slots__ = ("name", "pid", "args", "ann")
 
     def __init__(self, name: str, pid: str,
                  args: Optional[Dict[str, Any]]):
@@ -213,12 +262,14 @@ class _Span:
         self.args = args
 
     def __enter__(self):
+        self.ann = tracer.annotation(self.name, self.args)
         tracer.emit("B", self.name, self.pid, tracer.now(),
                     args=self.args)
         return self
 
     def __exit__(self, *exc):
         tracer.emit("E", self.name, self.pid, tracer.now())
+        self.ann.__exit__(None, None, None)
         return False
 
 
@@ -288,21 +339,18 @@ def instant(name: str, pid: str = "serving",
     tracer.emit("i", name, pid, tracer.now(), args=args)
 
 
-def enable(capacity: Optional[int] = None,
-           clock: Optional[str] = None) -> TraceRecorder:
-    """(Re)configure and start the process-wide recorder."""
-    if capacity is not None and capacity != tracer.capacity:
-        tracer.capacity = int(capacity)
-        tracer._events = deque(tracer._events, maxlen=tracer.capacity)
-    if clock is not None and clock != tracer.clock:
-        tracer.clock = clock
-        tracer._clock_ns = (time.monotonic_ns if clock == "mono"
-                            else time.perf_counter_ns)
+def enable(capacity: Optional[int] = None) -> TraceRecorder:
+    """(Re)configure and start the process-wide recorder: spans are
+    recorded and mirrored into the profiler, and garbage collections
+    are recorded as ``host.gc``."""
+    if capacity is not None:
+        tracer.resize(capacity)
     tracer.start()
     return tracer
 
 
 def stop() -> None:
+    """Stop recording and remove the garbage-collection hook."""
     tracer.stop()
 
 
@@ -352,21 +400,18 @@ def _dump_atexit() -> None:
 
 
 def configure_from_env() -> Optional[str]:
-    """Apply ``REPRO_TRACE`` / ``REPRO_TRACE_EVENTS`` /
-    ``REPRO_TRACE_CLOCK``.  Returns the dump path when tracing was
-    enabled by the environment, else ``None``.  Called once at import;
-    tests call it again after monkeypatching the environment."""
+    """Apply ``REPRO_TRACE`` / ``REPRO_TRACE_EVENTS``.  Returns the dump
+    path when tracing was enabled by the environment, else ``None``.
+    Called once at import; tests call it again after monkeypatching the
+    environment."""
     capacity = env_int("REPRO_TRACE_EVENTS", 65536, min_value=1)
-    clock = env_choice("REPRO_TRACE_CLOCK", "perf", ("perf", "mono"))
     path = env_path("REPRO_TRACE")
     if path is None:
-        # knobs still apply if tracing is later enabled explicitly
-        if capacity != tracer.capacity or clock != tracer.clock:
-            enable(capacity, clock)
-            tracer.stop()
+        # the capacity still applies if tracing is later enabled
+        tracer.resize(capacity)
         tracer._atexit_path = None
         return None
-    enable(capacity, clock)
+    enable(capacity)
     tracer._atexit_path = path
     return path
 

@@ -54,7 +54,8 @@ class _BatcherMixin:
 
     def _loop(self) -> None:
         while True:
-            req = self._queue.get()
+            with trace_span("batch.wait_request", "serving"):
+                req = self._queue.get()
             if req is None:
                 if self._running:
                     continue                # stray sentinel from a drain
@@ -138,8 +139,10 @@ class _BatcherMixin:
             if r._tspan is not None:
                 # closes the queue-wait window: submit -> this dispatch
                 r._tspan.lap("request.queue_wait", {"batch": bid})
-        self._stats.bump(batches=1, batched_rows=rows.shape[0])
-        self._put_completion((batch, executor, pending, rows, bid))
+        self._stats.bump(batches=1, batched_rows=rows.shape[0], _inflight=1)
+        with trace_span("batch.slot_wait", "serving",
+                        args=None if not tracer.enabled else {"batch": bid}):
+            self._put_completion((batch, executor, pending, rows, bid))
 
     def _put_completion(self, item: Tuple[Any, ...]) -> None:
         """Backpressured hand-off that cannot hang shutdown: the put
@@ -151,6 +154,7 @@ class _BatcherMixin:
                 return
             except queue.Full:
                 if not self._completer_alive:
+                    self._stats.bump(_inflight=-1)
                     for r in item[0]:
                         self._fail(r, RuntimeError(
                             "completion thread is not running"))
@@ -163,7 +167,10 @@ class _BatcherMixin:
                 item = self._completions.get()
                 if item is None:
                     break
-                self._complete_one(item)
+                try:
+                    self._complete_one(item)
+                finally:                    # delivered or failed
+                    self._stats.bump(_inflight=-1)
         finally:
             self._completer_alive = False
 
@@ -184,44 +191,50 @@ class _BatcherMixin:
                 for r in batch:
                     self._fail(r, e)
                 return
-        if self.is_range:
-            matches = np.asarray(out).reshape(rows, -1)
-            values = indices = None
-        else:
-            values, indices = out
-            # finalize shapes outputs for the *compiled module* (which
-            # may have been traced with 1-D or stacked queries); the
-            # scatter below is strictly row-major
-            values = np.asarray(values).reshape(rows, -1)
-            indices = np.asarray(indices).reshape(rows, -1)
-        now = time.perf_counter()
-        off = 0
-        for r in batch:
-            m = r.queries.shape[0]
-            if r.deadline is not None and now > r.deadline:
-                # result arrived, but past the budget: a miss, not a
-                # late delivery the client already gave up on
-                off += m
-                self._fail_timeout(r)
-                continue
+        # the wait for the device and the copy to the host
+        with trace_span("batch.transfer", "serving",
+                        args=None if not tracer.enabled else {"batch": bid}):
             if self.is_range:
-                r.result.matches = matches[off:off + m]
+                matches = np.asarray(out).reshape(rows, -1)
+                values = indices = None
             else:
-                r.result.values = values[off:off + m]
-                r.result.indices = indices[off:off + m]
-            r.result.completed_at = now
-            off += m
-            # one bump per delivered request: a snapshot can never see
-            # the request counted without its rows and latency sample
-            self._stats.bump(_latency_s=r.result.latency_s,
-                             _queue_s=r.result.queue_wait_s,
-                             _service_s=r.result.service_s,
-                             requests=1, queries=m)
-            if r._tspan is not None:
-                # dispatch -> delivery window, then the whole request
-                r._tspan.lap("request.service", {"batch": bid})
-                r._tspan.end()
-            r._settle()
+                values, indices = out
+                # finalize shapes outputs for the *compiled module* (which
+                # may have been traced with 1-D or stacked queries); the
+                # scatter below is strictly row-major
+                values = np.asarray(values).reshape(rows, -1)
+                indices = np.asarray(indices).reshape(rows, -1)
+        with trace_span("batch.deliver", "serving",
+                        args=None if not tracer.enabled else
+                        {"batch": bid, "requests": len(batch)}):
+            now = time.perf_counter()
+            off = 0
+            for r in batch:
+                m = r.queries.shape[0]
+                if r.deadline is not None and now > r.deadline:
+                    # result arrived, but past the budget: a miss, not a
+                    # late delivery the client already gave up on
+                    off += m
+                    self._fail_timeout(r)
+                    continue
+                if self.is_range:
+                    r.result.matches = matches[off:off + m]
+                else:
+                    r.result.values = values[off:off + m]
+                    r.result.indices = indices[off:off + m]
+                r.result.completed_at = now
+                off += m
+                # one bump per delivered request: a snapshot can never see
+                # the request counted without its rows and latency sample
+                self._stats.bump(_latency_s=r.result.latency_s,
+                                 _queue_s=r.result.queue_wait_s,
+                                 _service_s=r.result.service_s,
+                                 requests=1, queries=m)
+                if r._tspan is not None:
+                    # dispatch -> delivery window, then the whole request
+                    r._tspan.lap("request.service", {"batch": bid})
+                    r._tspan.end()
+                r._settle()
 
     def _fail(self, req: SearchRequest, err: BaseException) -> None:
         req.result.error = err

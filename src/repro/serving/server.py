@@ -354,7 +354,7 @@ class CamSearchServer(_BatcherMixin, _ResilienceMixin):
             "requests", "queries", "batches", "batched_rows", "errors",
             "gallery_updates", "rows_updated", "deadline_misses",
             "backend_errors", "retries", "degraded_batches",
-            "breaker_skips")
+            "breaker_skips", "inflight", "inflight_ahead")
 
     @property
     def stats(self) -> Dict[str, int]:
@@ -415,6 +415,7 @@ class CamSearchServer(_BatcherMixin, _ResilienceMixin):
                 return
             if item is None:
                 continue
+            self._stats.bump(_inflight=-1)
             for r in item[0]:
                 self._fail(r, RuntimeError(
                     "server stopped before completion"))

@@ -37,6 +37,11 @@ class ServerStats:
     counters plus the bounded latency window taken in one critical
     section.  Unknown counter names raise — a typo must not mint a new
     counter silently.
+
+    ``bump(_inflight=±1)`` moves the ``inflight`` gauge (batches
+    dispatched and not yet delivered); an entry first adds the gauge's
+    value to ``inflight_ahead``, so ``inflight_ahead / batches`` is the
+    mean number of batches ahead of a dispatched one.
     """
 
     def __init__(self, *names: str, window: int = 4096):
@@ -52,8 +57,14 @@ class ServerStats:
 
     def bump(self, _latency_s: Optional[float] = None,
              _queue_s: Optional[float] = None,
-             _service_s: Optional[float] = None, **deltas: int) -> None:
+             _service_s: Optional[float] = None, _inflight: int = 0,
+             **deltas: int) -> None:
         with self._lock:
+            if _inflight > 0:
+                self._counts["inflight_ahead"] += \
+                    _inflight * self._counts["inflight"]
+            if _inflight:
+                self._counts["inflight"] += _inflight
             for k, v in deltas.items():
                 if k not in self._counts:
                     raise KeyError(f"unknown stats counter {k!r}")

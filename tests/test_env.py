@@ -229,11 +229,6 @@ class TestEngineKnobsAreStrict:
         with pytest.raises(ValueError, match="REPRO_TRACE_EVENTS.*>= 1"):
             obs.configure_from_env()
         monkeypatch.delenv("REPRO_TRACE_EVENTS")
-        monkeypatch.setenv("REPRO_TRACE_CLOCK", "wall")
-        with pytest.raises(ValueError,
-                           match="REPRO_TRACE_CLOCK.*perf/mono"):
-            obs.configure_from_env()
-        monkeypatch.delenv("REPRO_TRACE_CLOCK")
         # an empty REPRO_TRACE is a shell quoting accident, not "off"
         monkeypatch.setenv("REPRO_TRACE", "")
         with pytest.raises(ValueError, match="REPRO_TRACE"):

@@ -21,7 +21,7 @@ import threading
 import numpy as np
 import pytest
 
-from repro.core import ArchSpec, compile_fn
+from repro.core import ArchSpec, RangeSpec, SimilaritySpec, compile_fn
 from repro.obs import trace as obs
 from repro.serving import CamSearchServer, CamServingGateway
 
@@ -46,14 +46,13 @@ def compiled():
 @pytest.fixture()
 def clean_tracer():
     """Tracing off and empty before and after; capacity restored."""
-    cap, clock = obs.tracer.capacity, obs.tracer.clock
+    cap = obs.tracer.capacity
     obs.stop()
     obs.tracer.clear()
     yield obs.tracer
     obs.stop()
     obs.tracer.clear()
-    obs.enable(cap, clock)
-    obs.stop()
+    obs.tracer.resize(cap)
 
 
 def _events(doc, ph=None, pid=None, name=None):
@@ -346,12 +345,202 @@ class TestEnvDrivenTracing:
         p = str(tmp_path / "t.json")
         monkeypatch.setenv("REPRO_TRACE", p)
         monkeypatch.setenv("REPRO_TRACE_EVENTS", "128")
-        monkeypatch.setenv("REPRO_TRACE_CLOCK", "mono")
         assert obs.configure_from_env() == p
         assert obs.tracer.enabled
         assert obs.tracer.capacity == 128
-        assert obs.tracer.clock == "mono"
         assert obs.tracer._atexit_path == p
         monkeypatch.delenv("REPRO_TRACE")
         assert obs.configure_from_env() is None
         assert obs.tracer._atexit_path is None
+
+
+def _serve(prog, gal, rng, requests=8, **kw):
+    """Submit ``requests`` two-row requests at once, wait for each and
+    stop the server."""
+    with CamSearchServer(prog, gal, max_wait_ms=1.0, **kw) as srv:
+        reqs = [srv.submit(rng.standard_normal((2, DIM)).astype(np.float32))
+                for _ in range(requests)]
+        for r in reqs:
+            r.wait(60)
+    return srv, reqs
+
+
+class TestBatchPipelineSpans:
+    def test_served_batch_spans_carry_the_dispatch_batch_id(
+            self, compiled, clean_tracer, rng):
+        prog, gal = compiled
+        obs.enable()
+        _serve(prog, gal, rng)
+        obs.stop()
+        doc = obs.to_chrome()
+        _assert_valid_chrome(doc)
+
+        def ids(name):
+            return sorted(e["args"]["batch"] for e in
+                          _events(doc, ph="B", pid="serving", name=name))
+
+        dispatched = ids("batch.dispatch")
+        assert dispatched
+        for name in ("batch.transfer", "batch.deliver", "batch.slot_wait"):
+            assert ids(name) == dispatched, name
+        delivered = _events(doc, ph="B", pid="serving", name="batch.deliver")
+        assert sum(e["args"]["requests"] for e in delivered) == 8
+        assert _events(doc, ph="B", pid="serving", name="batch.wait_request")
+
+    def test_gc_collection_recorded_and_hook_removed_on_stop(
+            self, clean_tracer):
+        import gc
+
+        before = list(gc.callbacks)
+        obs.enable()
+        gc.collect()
+        obs.stop()
+        assert gc.callbacks == before
+        coll = _events(obs.to_chrome(), ph="X", pid="host", name="host.gc")
+        full = [e for e in coll if e["args"]["generation"] == 2]
+        assert full and full[-1]["args"]["collected"] >= 0
+        assert full[-1]["dur"] > 0
+
+    def test_tracing_off_builds_no_annotation_and_no_gc_hook(
+            self, compiled, clean_tracer, rng, monkeypatch):
+        import gc
+
+        import jax.profiler
+
+        def refuse(*_a, **_kw):
+            raise AssertionError("TraceAnnotation built with tracing off")
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", refuse)
+        before = list(gc.callbacks)
+        prog, gal = compiled
+        _serve(prog, gal, rng)
+        gc.collect()
+        assert gc.callbacks == before
+        assert len(clean_tracer) == 0
+        # the patch is live: the same span with tracing on builds one
+        obs.enable()
+        with pytest.raises(AssertionError, match="TraceAnnotation"):
+            with obs.trace_span("plan.dispatch"):
+                pass
+
+    def test_profiler_capture_holds_program_spans(
+            self, compiled, clean_tracer, rng, tmp_path):
+        import glob
+
+        import jax
+
+        prog, gal = compiled
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        obs.enable()
+        with jax.profiler.trace(str(tmp_path), profiler_options=opts):
+            _serve(prog, gal, rng)
+        obs.stop()
+        path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                          recursive=True)
+        pd = jax.profiler.ProfileData.from_file(path)
+        host = [p for p in pd.planes if p.name.startswith("/host:")]
+        names = {e.name for p in host for ln in p.lines for e in ln.events}
+        for span in ("batch.dispatch", "plan.dispatch", "batch.finalize",
+                     "batch.deliver"):
+            assert span in names, span
+
+
+class TestInflightDepth:
+    @pytest.mark.parametrize("failing", [False, True])
+    def test_depth_bounded_and_gauge_back_to_zero_after_stop(
+            self, compiled, rng, monkeypatch, failing):
+        """``inflight_ahead / batches`` lies within ``[0, max_inflight +
+        1]`` (the completion queue plus the batch in the completer's
+        hands), and the gauge is 0 once the server has stopped, also
+        when a batch failed after its dispatch."""
+        prog, gal = compiled
+        kw = {}
+        if failing:
+            plan = prog.engine_plan
+            finalize, calls = plan.finalize, []
+
+            def first_fails(pending):
+                calls.append(1)
+                if len(calls) == 1:
+                    raise RuntimeError("device lost")
+                return finalize(pending)
+
+            def no_fallback(level):
+                if level != "primary":
+                    raise RuntimeError("no fallback")
+
+            monkeypatch.setattr(plan, "finalize", first_fails)
+            kw["fault_injector"] = no_fallback
+        srv, reqs = _serve(prog, gal, rng, requests=24, max_inflight=2,
+                           max_batch=4, **kw)
+        st = srv.stats
+        assert st["inflight"] == 0
+        assert st["batches"] >= 6
+        assert 0 <= st["inflight_ahead"] <= 3 * st["batches"]
+        failed = [r for r in reqs if r.result.error is not None]
+        assert st["errors"] == len(failed)
+        assert bool(failed) is failing
+
+
+def _sim_spec(dim, n=33):
+    return SimilaritySpec(
+        metric="eucl", k=K, largest=False, tile_rows=16, dims_per_tile=32,
+        grid_rows=-(-n // 16), grid_cols=-(-dim // 32), m=8, n=n, dim=dim,
+        query_arg=0, pattern_arg=1, out_v_shape=(8, K), out_i_shape=(8, K),
+        in_dtypes=("f32", "f32"))
+
+
+def _range_spec(dim, n=33):
+    return RangeSpec(
+        mode="threshold", metric="hamming", threshold=1.5, below=True,
+        tile_rows=16, dims_per_tile=32, grid_rows=-(-n // 16),
+        grid_cols=-(-dim // 32), m=8, n=n, dim=dim, query_arg=0,
+        pattern_args=(1,), out_shape=(8, n), in_dtypes=("f32", "f32"))
+
+
+def _lowered_chunk(builder, spec, *extra):
+    prepare, chunk_fn, _ = builder(spec, 8, *extra)
+    prepared = prepare(np.ones((spec.n, spec.dim), np.float32))
+    return chunk_fn.lower(np.ones((8, spec.dim), np.float32), prepared)
+
+
+class TestExecutableNames:
+    """The profiler names each XLA module after the function jitted:
+    every chunk executable carries its own stable name, and the
+    readers of device time find the search executable by its
+    ``chunk_fn`` part."""
+
+    @pytest.mark.parametrize("const, builder, spec, extra", [
+        ("SEARCH_SCAN_CHUNK", "_build_scan_executable", _sim_spec(64), ()),
+        ("SEARCH_TINY_CHUNK", "_build_tiny_executable", _sim_spec(32), ()),
+        ("SEARCH_SHARDED_CHUNK", "_build_sharded_executable",
+         _sim_spec(64), (1,)),
+        ("SEARCH_PALLAS_CHUNK", "_build_pallas_executable",
+         _sim_spec(64), ()),
+        ("RANGE_SCAN_CHUNK", "_build_range_scan_executable",
+         _range_spec(64), ()),
+        ("RANGE_TINY_CHUNK", "_build_tiny_range_executable",
+         _range_spec(32), ()),
+        ("RANGE_SHARDED_CHUNK", "_build_range_sharded_executable",
+         _range_spec(64), (1,)),
+        ("RANGE_PALLAS_CHUNK", "_build_range_pallas_executable",
+         _range_spec(64), ())])
+    def test_chunk_module_is_named_by_its_constant(self, const, builder,
+                                                   spec, extra):
+        import re
+
+        from repro.core.engine import executables as ex
+
+        text = _lowered_chunk(getattr(ex, builder), spec, *extra).as_text()
+        module = re.search(r"module @(\S+)", text).group(1)
+        assert module == "jit_" + getattr(ex, const)
+        assert "chunk_fn" in module
+
+    def test_scan_phases_are_named_scopes(self):
+        from repro.core.engine import executables as ex
+
+        text = _lowered_chunk(ex._build_scan_executable,
+                              _sim_spec(64)).as_text(debug_info=True)
+        for scope in ("cam.distances", "cam.tile_topk", "cam.merge_topk"):
+            assert scope in text, scope
