@@ -28,7 +28,8 @@ from .executables import (_build_pallas_executable,
                           _build_range_sharded_executable,
                           _build_scan_executable, _build_sharded_executable,
                           _build_tiny_executable,
-                          _build_tiny_range_executable)
+                          _build_tiny_range_executable, _dense_spec,
+                          tournament_geometry)
 from .plans import RangePlan, SearchPlan
 from .spec import (RangeSpec, _resolve_pack, extract_plan_spec,
                    extract_range_spec)
@@ -204,13 +205,22 @@ def get_plan(module: Module, *, backend: str = "jnp",
     if plan is not None:
         return plan
     tiny = _tiny_plan(spec, backend, s)
+    # the row-tile tournament's shape, which a search plan on the jnp
+    # backend records: read off the spec, it is no part of the key
+    geometry = {}
+    if not is_range and backend == "jnp":
+        group, steps = tournament_geometry(
+            _dense_spec(spec) if tiny else spec, b, s)
+        geometry = {"tiles_per_step": group, "scan_steps": steps}
     with trace_span("plan.compile",
                     args=None if not tracer.enabled else
                     {"family": "range" if is_range else "search",
                      "backend": backend, "batch": b, "shards": s,
-                     "packed": packed, "unroll": u}):
+                     "packed": packed, "unroll": u, **geometry}):
         plan = _build_leaf_plan(spec, backend, b, s, packed, tiny,
                                 is_range, u)
+        for name, value in geometry.items():
+            setattr(plan, name, value)
         _maybe_adopt_stored_exec(plan)
     return _cache_insert(key, plan)
 

@@ -99,15 +99,62 @@ def _col_dist_fn(spec: SimilaritySpec, packed: bool) -> Callable:
     return lambda qc, pr: kref.distances(qc, pr[0], phys_metric)
 
 
-def _tile_tournament(spec: SimilaritySpec, col_dist: Callable,
+#: elements of the ``(batch, G * tile_rows)`` float32 distance block one
+#: tournament step computes (32 MiB): enough work per step to hide the
+#: step's fixed cost (loop control, the two ``top_k`` calls, the merge).
+#: Of 2**21 to 2**26, 2**23 ran the SIFT1M-shape scans fastest on a TPU
+#: v5e (micro-batch 128, 256-row tiles: 8.4 ms for 1M x 128 float32,
+#: 10.3 ms for 1M x 256-bit codes, against 11.8 and 16.3 at 2**21)
+_STEP_ELEMS = 2 ** 23
+
+
+def row_group(tile_rows: int, tiles: int, batch: int) -> int:
+    """Row tiles per tournament step (``G``) for a scan over ``tiles``
+    row tiles at micro-batch ``batch``: read off the shape alone.
+
+    The step count is the fewest steps whose distance block stays
+    within :data:`_STEP_ELEMS`; ``G`` then spreads the tiles evenly
+    over those steps, so fewer than one step's worth of padding tiles
+    is ever added (see :func:`_tile_tournament`).
+    """
+    g_max = max(1, _STEP_ELEMS // (batch * tile_rows))
+    steps = -(-tiles // g_max)
+    return -(-tiles // steps)
+
+
+def tournament_geometry(spec: SimilaritySpec, batch: int,
+                        shards: int = 1) -> Tuple[int, int]:
+    """``(tiles_per_step, scan_steps)`` of the row-tile tournament a
+    (per-device) scan over ``spec``'s row tiles runs."""
+    tiles = -(-spec.grid_rows // shards)
+    group = row_group(spec.tile_rows, tiles, batch)
+    return group, -(-tiles // group)
+
+
+def _tile_tournament(spec: SimilaritySpec, col_dist: Callable, group: int,
                      unroll: int = 1):
     """The row-tile tournament shared by the single-device and sharded
-    executables: ``scan(qt, pt, roffs)`` runs the column-tile partial-sum
-    scan + per-tile top-k + vertical ``merge_topk`` tournament over the
-    row tiles in ``pt`` (physical domain), with global row offsets
-    ``roffs``.  ``pt`` is a tuple of pattern leaves (see
-    :func:`_col_dist_fn`), each ``(gr, gc, tr, lanes-or-dpt)``.  One
-    definition keeps every execution path bit-identical by construction.
+    executables.  ``scan(qt, pt, roff)`` runs the column-tile partial-sum
+    scan + top-k + vertical merge tournament over the row tiles in
+    ``pt`` (physical domain), whose first row is global row ``roff``.
+    ``pt`` is a tuple of pattern leaves (see :func:`_col_dist_fn`), each
+    ``(steps * group, gc, tr, lanes-or-dpt)``.  One definition keeps
+    every execution path bit-identical by construction.
+
+    Each scan step is one *execution block* of ``group`` consecutive
+    modelled subarrays (row tiles): one column-tile scan gives the
+    block's distances, one ``top_k`` over the block's rows its k best,
+    and those merge into the running list in one more ``top_k``, the
+    indices picked along — no gather.  This selects what the
+    tile-by-tile fold (``kref.cam_topk_tiled``) selects: both keep the
+    k best rows by value, the lower row first on a tie, and each row's
+    column partial sums keep their order.  Where fewer than k rows are
+    real (``n < k``) the fold fills the losing slots from the list of
+    tile 0, which seeds it: tile 0's masked rows at their real
+    positions, then sentinels.  So a masked row keeps its index only
+    inside tile 0; any other row past ``n`` (the ragged tail, tiles
+    padded onto the last step or onto a shard) is a ``pad_candidates``
+    sentinel.
 
     Shape-polymorphic in the query batch (read off ``qt``): the
     standard chunked path always traces at the plan's micro-batch, the
@@ -117,21 +164,29 @@ def _tile_tournament(spec: SimilaritySpec, col_dist: Callable,
     _, _, phys_largest = _metric_values(spec.metric, spec.largest)
     tr = spec.tile_rows
     n = spec.n
-    kk = min(k, tr)
+    span = group * tr                   # rows per step
+    kb = min(k, span)
     lose = -jnp.inf if phys_largest else jnp.inf
-    # rows beyond the unsharded physical extent exist only on shard-
-    # padding tiles; their candidates become pad_candidates sentinels
-    # (a no-op for the single-device grid, which never exceeds it)
-    n_phys = spec.grid_rows * tr
     # unroll is a tuning knob, never a semantic one: lax.scan executes
     # identical steps in identical order at any factor.  Clamp to each
     # scan's static length (the sharded executable scans tiles-per-
     # shard, not grid_rows, so the clamp reads the traced operands).
     unroll = max(1, int(unroll))
 
-    def tile_topk(qt, pr, roff):
-        """Per-row-tile candidate list (pr leaves: (gc, tr, ...))."""
+    def order(v):
+        """Ascending order of a physical value, best first; ``top_k``
+        takes its negation.  Negation is exact, so it also maps a key
+        back to its value."""
+        return -v if phys_largest else v
+
+    def candidates(qt, pr, roff):
+        """The block's k best rows, best first: ``(B, k)`` values and
+        global indices.  ``pr`` leaves are ``(group, gc, tr, ...)``."""
         batch = qt.shape[1]
+        # (gc, span, ...): one slab of the block's rows per column tile;
+        # a free reshape when gc == 1
+        cols = tuple(x.swapaxes(0, 1).reshape(x.shape[1], span,
+                                              *x.shape[3:]) for x in pr)
 
         def col_step(acc, xs):
             qc = xs[0]                  # horizontal merge, oracle arithmetic
@@ -139,34 +194,50 @@ def _tile_tournament(spec: SimilaritySpec, col_dist: Callable,
 
         with jax.named_scope("cam.distances"):
             dist, _ = jax.lax.scan(
-                col_step, jnp.zeros((batch, tr), jnp.float32), (qt, *pr),
+                col_step, jnp.zeros((batch, span), jnp.float32), (qt, *cols),
                 unroll=min(unroll, qt.shape[0]))
-        with jax.named_scope("cam.tile_topk"):
-            gidx = roff + jnp.arange(tr, dtype=jnp.int32)
-            dist = jnp.where(gidx[None, :] < n, dist, lose)  # ragged rows
-            key = dist if phys_largest else -dist
-            _, idx = jax.lax.top_k(key, kk)
-            v = jnp.take_along_axis(dist, idx, axis=-1)
+        with jax.named_scope("cam.block_topk"):
+            rows = roff + jnp.arange(span, dtype=jnp.int32)
+            dist = jnp.where(rows[None, :] < n, dist, lose)  # ragged rows
+            key, idx = jax.lax.top_k(-order(dist), kb)
             i = idx.astype(jnp.int32) + roff
-            i = jnp.where(i < n_phys, i, 2 ** 30)
-            return kref.pad_candidates(v, i, k, phys_largest)
+            i = jnp.where((i < n) | (i < tr), i, 2 ** 30)
+            return kref.pad_candidates(order(-key), i, k, phys_largest)
 
-    def scan(qt, pt, roffs):
-        def row_step(carry, xs):
-            cv, ci = carry                                   # vertical merge
-            tiles, roff = xs
-            v, i = tile_topk(qt, tiles, roff)
-            with jax.named_scope("cam.merge_topk"):
-                return kref.merge_topk(cv, ci, v, i, k=k,
-                                       largest=phys_largest), None
+    def select(v, i):
+        """The k best of candidate lists concatenated in ascending row
+        order: ``top_k`` keeps the earlier candidate on a tie, and each
+        index follows its value by a one-hot pick, not a gather."""
+        with jax.named_scope("cam.merge_topk"):
+            key, pos = jax.lax.top_k(-order(v), k)
+            hit = pos[:, :, None] == jnp.arange(v.shape[-1], dtype=pos.dtype)
+            return order(-key), jnp.where(hit, i[:, None, :], 0).sum(-1)
 
-        # tile 0 seeds the tournament (its padded-slot indices are real
-        # column positions, which the interpreter also reports), remaining
-        # row tiles stream through the scan.
-        init = tile_topk(qt, tuple(x[0] for x in pt), roffs[0])
-        (v, i), _ = jax.lax.scan(
-            row_step, init, (tuple(x[1:] for x in pt), roffs[1:]),
-            unroll=min(unroll, max(1, pt[0].shape[0] - 1)))
+    def scan(qt, pt, roff):
+        steps = pt[0].shape[0] // group
+        # the layout read in place, one block of tiles per step
+        blocks = tuple(x.reshape(steps, group, *x.shape[1:]) for x in pt)
+
+        def row_step(carry, s):
+            tiles = tuple(jax.lax.dynamic_index_in_dim(x, s, keepdims=False)
+                          for x in blocks)
+            cv, ci = candidates(qt, tiles, roff + s * span)
+            # the carried list (lower rows) goes first; on the first step
+            # it is a placeholder and goes last, so that step keeps block
+            # 0's own list, which seeds the tournament as tile 0's list
+            # seeds the fold
+            first = s == 0
+            v, i = (jnp.where(first, jnp.concatenate([new, old], axis=-1),
+                              jnp.concatenate([old, new], axis=-1))
+                    for old, new in zip(carry, (cv, ci)))
+            return select(v, i), None
+
+        batch = qt.shape[1]
+        placeholder = (jnp.full((batch, k), lose, jnp.float32),
+                       jnp.full((batch, k), 2 ** 30, jnp.int32))
+        (v, i), _ = jax.lax.scan(row_step, placeholder,
+                                 jnp.arange(steps, dtype=jnp.int32),
+                                 unroll=min(unroll, steps))
         return v, i
 
     return scan
@@ -313,24 +384,27 @@ def _build_scan_executable(spec: SimilaritySpec, batch: int,
     (reference-tiled) backend.
 
     ``chunk_fn`` mirrors ``kernels.ref.cam_topk_tiled`` exactly — same
-    partial-sum order, same stable top-k and tournament merges — but as a
-    ``jax.lax.scan`` over the (row_tile, col_tile) grid, so the jaxpr
-    stays small at any grid size and XLA pipelines the tiles.  With
-    ``packed=True`` the same scan runs over uint32 lane tiles
+    partial-sum order, the same selection as its per-tile top-k and
+    tournament merges — but as a ``jax.lax.scan`` over blocks of row
+    tiles (:func:`tournament_geometry`), so the jaxpr stays small at any
+    grid size and each step carries enough work to keep the device busy.
+    With ``packed=True`` the same scan runs over uint32 lane tiles
     (XOR+popcount partial counts) — identical integers, 1/32nd the
     resident gallery.
     """
     _, to_logical, _ = _metric_values(spec.metric, spec.largest)
-    gr, dim = spec.grid_rows, spec.dim
-    scan = _tile_tournament(spec, _col_dist_fn(spec, packed), unroll)
+    dim = spec.dim
+    group, steps = tournament_geometry(spec, batch)
+    scan = _tile_tournament(spec, _col_dist_fn(spec, packed), group, unroll)
 
     def prepare(p, care=None):
-        return _lay_patterns(p, care, spec, gr, packed)
+        # the row-tile axis padded to whole steps: padding tiles only
+        # ever yield losing sentinels
+        return _lay_patterns(p, care, spec, steps * group, packed)
 
     def chunk_fn(q, pt):
         qt = _layout_queries(q, spec, packed)
-        roffs = jnp.arange(gr, dtype=jnp.int32) * spec.tile_rows
-        v, i = scan(qt, pt, roffs)
+        v, i = scan(qt, pt, 0)
         return to_logical(v, float(dim)), i
 
     return (jax.jit(prepare), _named_jit(chunk_fn, name),
@@ -373,10 +447,11 @@ def _build_sharded_executable(spec: SimilaritySpec, batch: int, shards: int,
     over a device mesh.
 
     Device ``d`` holds row tiles ``[d*tps, (d+1)*tps)`` of the padded
-    gallery (``tps = ceil(grid_rows / shards)``) and runs the *same*
-    row-tile scan as the single-device executable over its shard — the
-    bank level of the paper's hierarchy.  ``chunk_fn`` returns the
-    per-device candidate lists still *sharded* ``(shards, batch, k)``;
+    gallery (``tps``: ``ceil(grid_rows / shards)`` rounded up to whole
+    tournament steps) and runs the *same* row-tile scan as the
+    single-device executable over its shard — the bank level of the
+    paper's hierarchy.  ``chunk_fn`` returns the per-device candidate
+    lists still *sharded* ``(shards, batch, k)``;
     the cross-device tournament happens in :func:`merge_shard_candidates`
     at result-materialisation time.
 
@@ -388,20 +463,21 @@ def _build_sharded_executable(spec: SimilaritySpec, batch: int, shards: int,
     chunk after chunk back-to-back; the merge is O(shards·k) per query
     and runs off-stream.
 
-    Padding tiles introduced by uneven division live *beyond* the
-    single-device physical row count ``grid_rows * tile_rows``; their
+    Padding tiles introduced by uneven division (and by rounding ``tps``
+    up to whole steps) live *beyond* the single-device physical row
+    count ``grid_rows * tile_rows``; their
     candidates are rewritten to the ``pad_candidates`` sentinels
     (losing value, index ``2**30``) so a sharded plan emits bit-identical
     output to the unsharded one even when ``n < k`` leaves losing slots
     visible.
     """
     _, to_logical, _ = _metric_values(spec.metric, spec.largest)
-    tr, gr = spec.tile_rows, spec.grid_rows
-    dim = spec.dim
+    tr, dim = spec.tile_rows, spec.dim
     mesh = make_data_mesh(shards)
-    tps = -(-gr // shards)          # row tiles per shard
+    group, steps = tournament_geometry(spec, batch, shards)
+    tps = steps * group             # row tiles per shard, whole steps
     gr_pad = shards * tps
-    scan = _tile_tournament(spec, _col_dist_fn(spec, packed), unroll)
+    scan = _tile_tournament(spec, _col_dist_fn(spec, packed), group, unroll)
 
     def prepare(p, care=None):
         pt = _lay_patterns(p, care, spec, gr_pad, packed)
@@ -413,8 +489,7 @@ def _build_sharded_executable(spec: SimilaritySpec, batch: int, shards: int,
     def local_scan(qt, pt):
         """One device's shard of the row-tile tournament (no collectives)."""
         d = jax.lax.axis_index("data")
-        roffs = (d * tps + jnp.arange(tps, dtype=jnp.int32)) * tr
-        v, i = scan(qt, pt, roffs)
+        v, i = scan(qt, pt, d * (tps * tr))
         # logical-domain conversion is elementwise and strictly monotone,
         # so the host-side merge can run directly on logical values with
         # the logical polarity and still match the physical tournament

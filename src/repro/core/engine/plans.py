@@ -11,7 +11,7 @@ relay) is inherited.
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax.numpy as jnp
 import numpy as np
@@ -33,6 +33,11 @@ class SearchPlan(PlanBase):
     """
 
     family: str = field(default="search", repr=False)
+    #: the row-tile tournament's shape (jnp backend; ``None`` for the
+    #: fused Pallas kernel, which has no scan): row tiles per scan step
+    #: and scan steps per micro-batch (per device when sharded)
+    tiles_per_step: Optional[int] = None
+    scan_steps: Optional[int] = None
 
     def _stored_sources(self, inputs) -> Tuple:
         spec = self.spec

@@ -173,6 +173,15 @@ def test_plan_chunk_compiles_for_v5e(family, backend, one_chip, monkeypatch):
         lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
         prepared)
     compiled = plan._chunk_fn.lower(q, prepared).compile()
-    assert ("tpu_custom_call" in compiled.as_text()) == (backend == "pallas")
+    text = compiled.as_text()
+    assert ("tpu_custom_call" in text) == (backend == "pallas")
+    if backend == "jnp":
+        # the row tiles run in blocks, several per scan step, read in
+        # place from the layout padded to whole steps, with no gather
+        assert plan.tiles_per_step > 1
+        tiles = plan.tiles_per_step * plan.scan_steps
+        assert tiles >= plan.spec.grid_rows > tiles - plan.scan_steps
+        assert all(x.shape[0] == tiles for x in prepared)
+        assert "gather(" not in text
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 8 * 2 ** 30

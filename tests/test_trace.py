@@ -542,5 +542,5 @@ class TestExecutableNames:
 
         text = _lowered_chunk(ex._build_scan_executable,
                               _sim_spec(64)).as_text(debug_info=True)
-        for scope in ("cam.distances", "cam.tile_topk", "cam.merge_topk"):
+        for scope in ("cam.distances", "cam.block_topk", "cam.merge_topk"):
             assert scope in text, scope
